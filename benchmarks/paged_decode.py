@@ -171,6 +171,9 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    from repro.jax_cache import enable_compile_cache
+
+    enable_compile_cache()
     # --fast: same tiny smoke workload — the flag exists for CI-invocation
     # parity with the other serving benchmarks (both legs are already sized
     # for a sub-minute run on the smoke model)

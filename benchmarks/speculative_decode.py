@@ -126,4 +126,7 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    from repro.jax_cache import enable_compile_cache
+
+    enable_compile_cache()
     main()

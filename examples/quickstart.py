@@ -9,7 +9,10 @@ from repro.configs.registry import get_config, list_archs
 from repro.core import Request, SimConfig, Simulation, StraightLinePolicy, Thresholds, Tier
 from repro.core.testbed import paper_tiers
 from repro.core.workload import ramp
+from repro.jax_cache import enable_compile_cache
 from repro.models import get_model
+
+enable_compile_cache()
 
 print("assigned architectures:", ", ".join(list_archs()))
 

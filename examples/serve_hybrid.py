@@ -52,9 +52,11 @@ from repro.core import (
 )
 from repro.configs.registry import get_config
 from repro.core.router import Backend, StraightLineRouter
+from repro.jax_cache import enable_compile_cache
 from repro.serving.engine import PagedEngineConfig, PagedInferenceEngine
 from repro.serving.scheduler import EngineLoop
 
+enable_compile_cache()
 CFG = get_config("smollm-360m", smoke=True).replace(attn_chunk=64)
 MAXLEN, NEW, PROMPT = 96, 8, 8
 PS = 16

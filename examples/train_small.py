@@ -7,6 +7,7 @@ import shutil
 import tempfile
 
 from repro.configs.registry import get_config
+from repro.jax_cache import enable_compile_cache
 from repro.models import get_model
 from repro.train import checkpoint as ckpt
 from repro.train.data import DataConfig
@@ -19,6 +20,7 @@ ap.add_argument("--steps", type=int, default=120)
 ap.add_argument("--arch", default="smollm-360m")
 ap.add_argument("--ckpt-dir", default=None)
 args = ap.parse_args()
+enable_compile_cache()
 
 ckpt_dir = args.ckpt_dir or tempfile.mkdtemp(prefix="straightline_ckpt_")
 cfg = get_config(args.arch, smoke=True).replace(attn_chunk=16, ce_chunks=2)

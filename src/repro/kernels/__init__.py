@@ -1,3 +1,15 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
+"""Pallas kernels for the serving hot path (paged decode attention and its
+prefill-write twin, flash and dense decode attention, rmsnorm, chunkwise
+mLSTM). Each kernel package holds ``kernel.py`` (the ``pallas_call``),
+``ops.py`` (the model-facing wrapper) and ``ref.py`` (the jnp oracle)."""
+from __future__ import annotations
+
+import jax
+
+
+def interpret() -> bool:
+    """Whether Pallas kernels run in the interpreter: exactly when JAX's
+    default backend is the CPU, which has no Mosaic compiler. Every
+    ``ops.py`` asks at trace time, so a TPU run always compiles its
+    kernels."""
+    return jax.default_backend() == "cpu"

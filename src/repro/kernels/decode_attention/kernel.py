@@ -67,7 +67,8 @@ def decode_attention_grouped(
     v: jax.Array,
     lengths: jax.Array,  # (B,) int32 valid prefix per sequence
     bt: int = 128,
-    interpret: bool = True,
+    *,
+    interpret: bool,
 ) -> jax.Array:
     B, KV, G, hd = q.shape
     T = k.shape[2]

@@ -1,13 +1,10 @@
 """Model-facing wrapper: (B, 1, H, hd) q + (B, T, KV, hd) cache layout."""
 from __future__ import annotations
 
-import os
-
 import jax.numpy as jnp
 
+from repro.kernels import interpret
 from repro.kernels.decode_attention.kernel import decode_attention_grouped
-
-_INTERPRET = os.environ.get("REPRO_PALLAS_INTERPRET", "1") != "0"
 
 
 def decode_attention(q, k, v, cache_len, bt: int = 128):
@@ -25,5 +22,5 @@ def decode_attention(q, k, v, cache_len, bt: int = 128):
         kk = jnp.pad(kk, ((0, 0), (0, 0), (0, pad), (0, 0)))
         vv = jnp.pad(vv, ((0, 0), (0, 0), (0, pad), (0, 0)))
     qg = q[:, 0].reshape(B, KV, G, hd)
-    o = decode_attention_grouped(qg, kk, vv, lens, bt=bt, interpret=_INTERPRET)
+    o = decode_attention_grouped(qg, kk, vv, lens, bt=bt, interpret=interpret())
     return o.reshape(B, 1, H, hd)

@@ -69,7 +69,8 @@ def flash_attention_bhsd(
     v: jax.Array,
     bq: int = 128,
     bkv: int = 128,
-    interpret: bool = True,
+    *,
+    interpret: bool,
 ) -> jax.Array:
     B, H, S, hd = q.shape
     KV = k.shape[1]

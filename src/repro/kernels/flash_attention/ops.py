@@ -1,17 +1,10 @@
-"""Jit'd model-facing wrapper: (B, S, H, hd) layout + padding + layout swap.
-
-``interpret`` defaults to True because this container is CPU-only; on TPU
-set REPRO_PALLAS_INTERPRET=0 (or pass interpret=False).
-"""
+"""Model-facing wrapper: (B, S, H, hd) layout + padding + layout swap."""
 from __future__ import annotations
-
-import os
 
 import jax.numpy as jnp
 
+from repro.kernels import interpret
 from repro.kernels.flash_attention.kernel import flash_attention_bhsd
-
-_INTERPRET = os.environ.get("REPRO_PALLAS_INTERPRET", "1") != "0"
 
 
 def flash_attention(q, k, v, pos_q=None, pos_k=None, bq: int = 128, bkv: int = 128):
@@ -31,7 +24,7 @@ def flash_attention(q, k, v, pos_q=None, pos_k=None, bq: int = 128, bkv: int = 1
         v.transpose(0, 2, 1, 3),
         bq=bq,
         bkv=bkv,
-        interpret=_INTERPRET,
+        interpret=interpret(),
     )
     o = o.transpose(0, 2, 1, 3)
     return o[:, :S] if pad else o

@@ -90,7 +90,8 @@ def mlstm_chunkwise_bh(
     i: jax.Array,   # (BH, S, 1) input-gate preactivation
     lf: jax.Array,  # (BH, S, 1) log-sigmoid forget gate
     chunk: int = 64,
-    interpret: bool = True,
+    *,
+    interpret: bool,
 ):
     BH, S, DH = q.shape
     assert S % chunk == 0, (S, chunk)

@@ -1,14 +1,11 @@
 """Model-facing wrapper matching models/xlstm.py's chunkwise signature."""
 from __future__ import annotations
 
-import os
-
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import interpret
 from repro.kernels.mlstm_chunk.kernel import mlstm_chunkwise_bh
-
-_INTERPRET = os.environ.get("REPRO_PALLAS_INTERPRET", "1") != "0"
 
 
 def mlstm_chunkwise(q, k, v, i, f, C0, n0, m0, chunk: int = 64):
@@ -27,7 +24,7 @@ def mlstm_chunkwise(q, k, v, i, f, C0, n0, m0, chunk: int = 64):
     if S % chunk != 0:
         chunk = S
     h, Cf, nf, mf = mlstm_chunkwise_bh(
-        bh(q), bh(k), bh(v), bh1(i), bh1(lf), chunk=chunk, interpret=_INTERPRET
+        bh(q), bh(k), bh(v), bh1(i), bh1(lf), chunk=chunk, interpret=interpret()
     )
     h = h.reshape(B, NH, S, DH).transpose(0, 2, 1, 3)
     C = Cf.reshape(B, NH, DH, DH)

@@ -101,7 +101,8 @@ def paged_attention_grouped(
     pool_v: jax.Array,
     block_tab: jax.Array,  # (B, P) physical pages — or (B, W1) l1 rows (chained)
     lengths: jax.Array,    # (B,) int32 valid tokens per sequence
-    interpret: bool = True,
+    *,
+    interpret: bool,
     softcap: float = 0.0,
     pool_ks: jax.Array | None = None,   # (num_pages, KV, ps, 1) bf16 scales
     pool_vs: jax.Array | None = None,
@@ -205,7 +206,8 @@ def paged_prefill_write_grouped(
     k: jax.Array,          # (1, Lp, KV, hd) — Lp a multiple of ps
     v: jax.Array,
     tab_row: jax.Array,    # (P,) int32, P >= Lp // ps
-    interpret: bool = True,
+    *,
+    interpret: bool,
 ):
     """Returns (new_pool_k, new_pool_v); Lp % ps must be 0 (bucketed prefill
     guarantees it — ops.py falls back to the jnp ref for ragged lengths)."""
@@ -221,8 +223,8 @@ def paged_prefill_write_grouped(
             pl.BlockSpec((1, ps, KV, hd), lambda ib, tab: (0, ib, 0, 0)),
             pl.BlockSpec((1, ps, KV, hd), lambda ib, tab: (0, ib, 0, 0)),
             # the pools stay in place (aliased outputs); no copy-in
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=[
             # the scatter: chunk ib of the prompt lands in page tab[ib]
@@ -270,7 +272,8 @@ def paged_prefill_write_grouped_quant(
     k: jax.Array,          # (1, Lp, KV, hd) fp activations — Lp % ps == 0
     v: jax.Array,
     tab_row: jax.Array,    # (P,) int32, P >= Lp // ps
-    interpret: bool = True,
+    *,
+    interpret: bool,
 ):
     """Returns (new_pool_k, new_pool_v, new_pool_ks, new_pool_vs)."""
     num_pages, KV, ps, hd = pool_k.shape
@@ -284,10 +287,10 @@ def paged_prefill_write_grouped_quant(
         in_specs=[
             pl.BlockSpec((1, ps, KV, hd), lambda ib, tab: (0, ib, 0, 0)),
             pl.BlockSpec((1, ps, KV, hd), lambda ib, tab: (0, ib, 0, 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=[
             pl.BlockSpec((1, KV, ps, hd), lambda ib, tab: (tab[ib], 0, 0, 0)),

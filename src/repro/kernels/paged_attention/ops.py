@@ -6,10 +6,9 @@ alongside the value pools — quantize at write, dequantize on gather) and the
 decode path additionally accepts chained two-level block tables."""
 from __future__ import annotations
 
-import os
-
 import jax.numpy as jnp
 
+from repro.kernels import interpret
 from repro.kernels.paged_attention.kernel import (
     paged_attention_grouped,
     paged_prefill_write_grouped,
@@ -24,8 +23,6 @@ from repro.kernels.paged_attention.ref import (
     paged_verify_write_ref,
 )
 from repro.models.quant import dequantize_kv
-
-_INTERPRET = os.environ.get("REPRO_PALLAS_INTERPRET", "1") != "0"
 
 
 def _shift_row(tab, offset, ps):
@@ -64,7 +61,7 @@ def paged_prefill_write(pool_k, pool_v, k, v, tab_row, use_pallas: bool = True,
     if offset is not None:
         tab = _shift_row(tab, offset, ps)
     if use_pallas and Lp % ps == 0:
-        return paged_prefill_write_grouped(pool_k, pool_v, k, v, tab, interpret=_INTERPRET)
+        return paged_prefill_write_grouped(pool_k, pool_v, k, v, tab, interpret=interpret())
     return paged_prefill_write_ref(pool_k, pool_v, k, v, tab)
 
 
@@ -81,7 +78,7 @@ def paged_prefill_write_quant(pool_k, pool_v, pool_ks, pool_vs, k, v, tab_row,
         tab = _shift_row(tab, offset, ps)
     if use_pallas and Lp % ps == 0:
         return paged_prefill_write_grouped_quant(
-            pool_k, pool_v, pool_ks, pool_vs, k, v, tab, interpret=_INTERPRET
+            pool_k, pool_v, pool_ks, pool_vs, k, v, tab, interpret=interpret()
         )
     return paged_prefill_write_quant_ref(pool_k, pool_v, pool_ks, pool_vs, k, v, tab)
 
@@ -145,7 +142,7 @@ def paged_attention(q, pool_k, pool_v, block_tab, lengths, use_pallas: bool = Tr
     l2 = None if l2_tab is None else jnp.asarray(l2_tab, jnp.int32)
     if use_pallas:
         o = paged_attention_grouped(
-            qg, pool_k, pool_v, tab, lens, interpret=_INTERPRET, softcap=softcap,
+            qg, pool_k, pool_v, tab, lens, interpret=interpret(), softcap=softcap,
             pool_ks=pool_ks, pool_vs=pool_vs, l2_tab=l2,
         )
     else:

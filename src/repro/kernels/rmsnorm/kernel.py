@@ -18,7 +18,7 @@ def _kernel(x_ref, w_ref, o_ref, *, eps):
 
 @functools.partial(jax.jit, static_argnames=("eps", "block_rows", "interpret"))
 def rmsnorm_2d(x: jax.Array, w: jax.Array, eps: float = 1e-6,
-               block_rows: int = 256, interpret: bool = True) -> jax.Array:
+               block_rows: int = 256, *, interpret: bool) -> jax.Array:
     R, D = x.shape
     block_rows = min(block_rows, R)
     assert R % block_rows == 0, (R, block_rows)

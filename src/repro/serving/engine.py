@@ -1252,31 +1252,53 @@ class PagedInferenceEngine(_EngineBase):
         return min(self.free_slots(), pages // per_seq)
 
     # -- public API -------------------------------------------------------------
+    def _null_prefill(self, Lp: int, slot: int):
+        """The prefill program traffic runs, with its arguments for a zero
+        prompt of shape ``Lp`` through an all-null block-table row. With
+        chunked prefill on — or the prefix cache, whose admissions all ride
+        the chunk machinery — that is the CHUNK program. Returns (jitted
+        fn, args); the fn's second output is the new cache."""
+        toks = jnp.zeros(Lp, jnp.int32)
+        row = jnp.full(self._row_width, NULL_PAGE, jnp.int32)
+        if self._chunk_tokens or self.prefix_cache is not None:
+            return self._prefill_chunk, (
+                self.params, self.cache, toks, row, jnp.asarray(slot),
+                jnp.asarray(0), jnp.asarray(1), self.model.init_chunk_state(),
+            )
+        return self._prefill, (
+            self.params, self.cache, toks, row, jnp.asarray(slot), jnp.asarray(1),
+        )
+
+    def _decode_args(self) -> tuple:
+        """Arguments of the batched decode step: the slot state plus the
+        flat block table, or both levels of the chained one."""
+        tabs = (self.chain.l1, self.chain.l2) if self.chain is not None else (self.block_tab,)
+        return (
+            self.params, self.cache, jnp.asarray(self._last), jnp.asarray(self.slot_len),
+            *(jnp.asarray(t) for t in tabs),
+        )
+
     def _prewarm_shape(self, Lp: int, slot: int) -> None:
         """Compile a paged prefill at shape ``Lp`` through an all-null
         block-table row: K/V writes land on the reserved null page (garbage
         by design) and the idle slot's recurrent state is rewritten from
         zero on any real install. The cache is reassigned because the paged
-        prefill donates its buffer. With chunked prefill on — or the prefix
-        cache, whose admissions all ride the chunk machinery — the CHUNK
-        path is what traffic runs, so that is what gets compiled."""
-        toks = np.zeros(Lp, np.int32)
-        row = np.full(self._row_width, NULL_PAGE, np.int32)
-        if self._chunk_tokens or self.prefix_cache is not None:
-            _, self.cache, _ = self._prefill_chunk(
-                self.params, self.cache, jnp.asarray(toks), jnp.asarray(row),
-                jnp.asarray(slot), jnp.asarray(0), jnp.asarray(1),
-                self.model.init_chunk_state(),
-            )
-            return
-        _, self.cache = self._prefill(
-            self.params,
-            self.cache,
-            jnp.asarray(toks),
-            jnp.asarray(row),
-            jnp.asarray(slot),
-            jnp.asarray(1),
-        )
+        prefill donates its buffer."""
+        fn, args = self._null_prefill(Lp, slot)
+        self.cache = fn(*args)[1]
+
+    def lowered_programs(self) -> Dict[str, str]:
+        """StableHLO text of the decode step and of the largest prefill
+        program, lowered (not compiled, not run) for this engine's shapes:
+        what a caller reads to check which kernels the served programs
+        hold (a Pallas kernel compiled for the TPU is a
+        ``tpu_custom_call``)."""
+        with self.lock:
+            fn, args = self._null_prefill(self._bucket_len(self._shape_cap, self._shape_cap), 0)
+            return {
+                "decode": self._decode.lower(*self._decode_args()).as_text(),
+                "prefill": fn.lower(*args).as_text(),
+            }
 
     def submit(self, prompt: List[int], trace=None) -> int:
         # Gate on the engine's RESOLVED length cap, not raw max_seq_len: in
@@ -1600,23 +1622,7 @@ class PagedInferenceEngine(_EngineBase):
                 active = [i for i in active if i not in set(sped)]
             finished, self._just_finished = self._just_finished, []
             if active:
-                if self.chain is not None:
-                    nxt, self.cache = self._decode(
-                        self.params,
-                        self.cache,
-                        jnp.asarray(self._last),
-                        jnp.asarray(self.slot_len),
-                        jnp.asarray(self.chain.l1),
-                        jnp.asarray(self.chain.l2),
-                    )
-                else:
-                    nxt, self.cache = self._decode(
-                        self.params,
-                        self.cache,
-                        jnp.asarray(self._last),
-                        jnp.asarray(self.slot_len),
-                        jnp.asarray(self.block_tab),
-                    )
+                nxt, self.cache = self._decode(*self._decode_args())
                 nxt = np.asarray(nxt)
                 tok_t = time.monotonic()      # one stamp per batched decode step
                 for i in active:

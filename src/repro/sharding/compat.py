@@ -1,26 +1,13 @@
-"""JAX version compatibility for shard_map.
-
-JAX >= 0.6 exposes ``jax.shard_map`` with the replication-check kwarg
-``check_vma``; older releases only have ``jax.experimental.shard_map`` with
-``check_rep``. Importing from here keeps every call site on one shim.
+"""shard_map with the replication check off, for every call site.
 
     from repro.sharding.compat import shard_map_nocheck
     fn = shard_map_nocheck(body, mesh=mesh, in_specs=..., out_specs=...)
 """
 from __future__ import annotations
 
-try:
-    from jax import shard_map as _shard_map
-
-    _CHECK_KW = "check_vma"
-except ImportError:  # pragma: no cover — older JAX
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    _CHECK_KW = "check_rep"
+from jax import shard_map
 
 
 def shard_map_nocheck(fn, *, mesh, in_specs, out_specs):
-    """shard_map with the (version-appropriate) replication check disabled."""
-    return _shard_map(
-        fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **{_CHECK_KW: False}
-    )
+    """``jax.shard_map`` with the replication check (``check_vma``) disabled."""
+    return shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False)

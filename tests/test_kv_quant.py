@@ -205,13 +205,15 @@ def _motif_prompts(seed, n, length):
 # kernel-level test above), so on prompts whose greedy gaps exceed it the
 # token streams must be identical. The prompt seeds are fixed and the whole
 # pipeline is deterministic — each variant exercises its path for real
-# (two chunks, accepted proposals, actual preemptions).
+# (two chunks, accepted proposals, actual preemptions). The random weights
+# come from JAX's default threefry stream (partitionable since JAX 0.5), so
+# a change of PRNG implementation calls for the seeds to be chosen again.
 VARIANTS = {
     "plain":   dict(kw={}, num_pages=33, prompts=_rand_prompts(102, 4, 4)),
     "chunked": dict(kw={"chunk_tokens": 16}, num_pages=65,
                     prompts=_rand_prompts(200, 3, 20)),       # 16+4: two chunks
     "spec":    dict(kw={"spec_tokens": 3}, num_pages=65,
-                    prompts=_motif_prompts(301, 3, 14)),
+                    prompts=_motif_prompts(310, 3, 14)),
     "preempt": dict(kw={}, num_pages=10, prompts=_rand_prompts(102, 4, 4)),
 }
 

@@ -17,19 +17,6 @@ def run_in_subprocess(body: str):
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import NamedSharding, PartitionSpec as P
-        if not hasattr(jax.sharding, "AxisType"):
-            # older JAX: meshes are implicitly Auto-typed; accept and drop
-            # the axis_types kwarg so the test bodies run unchanged
-            import enum
-            class _AxisType(enum.Enum):
-                Auto = "auto"
-                Explicit = "explicit"
-                Manual = "manual"
-            jax.sharding.AxisType = _AxisType
-            _real_make_mesh = jax.make_mesh
-            def _make_mesh(axis_shapes, axis_names, *, axis_types=None, **kw):
-                return _real_make_mesh(axis_shapes, axis_names, **kw)
-            jax.make_mesh = _make_mesh
         """
     ) + textwrap.dedent(body)
     r = subprocess.run(

@@ -123,9 +123,12 @@ def test_preemption_mid_speculation_restart_parity(cfg):
     )
     a = ample.generate(prompts)
     assert ample.preemptions == 0 and ample.spec_accepted > 0
+    # 19 usable pages against 4 x 8 at full length: sequences that finish
+    # early on long accepted runs free theirs, so the pool must be well
+    # short of the sum for the growth of the rest to preempt
     tight = PagedInferenceEngine(
         cfg,
-        PagedEngineConfig(page_size=4, num_pages=24, max_slots=4,
+        PagedEngineConfig(page_size=4, num_pages=20, max_slots=4,
                           max_seq_len=64, max_new_tokens=24, spec_tokens=4),
         params=ample.params,
     )
