@@ -185,8 +185,8 @@ traces = tracer.traces()
 assert len(traces) == N, (len(traces), N)
 for t in traces:
     names = [s["name"] for s in t["spans"]]
-    assert "placement" in names, t["rid"]
-    placement = next(s for s in t["spans"] if s["name"] == "placement")
+    assert "serve.router.place" in names, t["rid"]
+    placement = next(s for s in t["spans"] if s["name"] == "serve.router.place")
     assert {"f_t", "flask_free", "docker_free", "tier"} <= set(placement["attrs"])
 hedged = [t for t in traces if any(e["name"] == "hedge_fired" for e in t["events"])]
 dual = [t for t in hedged

@@ -69,7 +69,7 @@ from repro.core.telemetry import (
     default_registry,
     warm_fraction,
 )
-from repro.core.tracing import Tracer
+from repro.core.tracing import Tracer, span
 
 
 class RequestFailed(RuntimeError):
@@ -291,11 +291,18 @@ class StraightLineRouter:
             else None
         )
         req.trace = tr
+        with span("serve.router.place", tr) as sp:
+            return self._place(req, now, sp)
+
+    def _place(self, req: Request, now: float, sp: span) -> Tier:
+        """Algorithm 1 and the enqueue for ``submit``, inside its
+        ``serve.router.place`` span ``sp``."""
+        tr = req.trace
         with self._lock:
             self.freq.observe(now)
             f_t = self.freq.frequency(now)
         # availability snapshots + the warm-up state actually consumed are
-        # Algorithm 1's inputs — captured into the placement span so a trace
+        # Algorithm 1's inputs — recorded on the placement span so a trace
         # answers "why this tier"
         flask_free, docker_free = self._free(Tier.FLASK), self._free(Tier.DOCKER)
         warm_seen: Dict[str, object] = {}
@@ -310,12 +317,11 @@ class StraightLineRouter:
             self._policy_takes_warmup,
         )
         tier = d.tier
+        sp.set(tier=tier.name)
         if tr is not None:
             warm = warm_seen.get("w")
-            tr.add_span(
-                "placement", now, self.clock(),
-                f_t=f_t, flask_free=flask_free, docker_free=docker_free,
-                tier=tier.name, reason=d.reason,
+            sp.set(
+                f_t=f_t, flask_free=flask_free, docker_free=docker_free, reason=d.reason,
                 warmth={
                     t.name: (v["warmth"] if isinstance(v, dict) else v)
                     for t, v in warm.items()

@@ -173,7 +173,7 @@ class Simulation:
                     )
                     if req.trace is not None:
                         req.trace.add_span(
-                            "placement", now, now, f_t=f_t, flask_free=flask_free,
+                            "serve.router.place", now, now, f_t=f_t, flask_free=flask_free,
                             docker_free=docker_free, tier=d.tier.name, reason=d.reason,
                         )
                 self._submit(req, d.tier, now)

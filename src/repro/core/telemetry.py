@@ -238,49 +238,15 @@ class CapacityGauge:
         sweeps."""
         return list(self._stats)
 
-    def warmth(self, name: str) -> Optional[float]:
-        """Warm-up fraction for ``name`` (compile progress), or None."""
-        return warm_fraction(self.stats(name))
-
     def occupancy(self, name: str) -> Optional[float]:
         """Decode-batch occupancy for ``name`` (continuous-batching
         interleaving), or None when the stats probe exports no slots."""
         return batch_occupancy(self.stats(name))
 
-    def queue_depth(self, name: str) -> Optional[int]:
-        """Admitted-but-waiting depth behind ``name``'s step loop, or None."""
-        return queue_depth(self.stats(name))
-
     def prefill_backlog(self, name: str) -> Optional[int]:
         """Unabsorbed prompt tokens behind ``name``'s chunked prefill, or
         None when the stats probe does not export a backlog."""
         return prefill_backlog(self.stats(name))
-
-    def cached_pages(self, name: str) -> Optional[int]:
-        """Prefix-cache pages held warm by ``name``, or None (no cache)."""
-        return cached_pages(self.stats(name))
-
-    def prefix_hit_rate(self, name: str) -> Optional[float]:
-        """Prefix-cache hit rate for ``name``, or None (no cache)."""
-        return prefix_hit_rate(self.stats(name))
-
-    def reclaimable_pages(self, name: str) -> Optional[int]:
-        """Free + evictable-cache pages for ``name`` — the capacity view
-        that counts cold prefix-cache leaves as reclaimable."""
-        return reclaimable_pages(self.stats(name))
-
-    def spec_acceptance(self, name: str) -> Optional[float]:
-        """Speculative-decode acceptance rate for ``name``, or None when
-        speculation is off or nothing has been proposed yet."""
-        return spec_acceptance(self.stats(name))
-
-    def kv_bytes_per_token(self, name: str) -> Optional[float]:
-        """KV-cache bytes per cached token for ``name``, or None."""
-        return kv_bytes_per_token(self.stats(name))
-
-    def kv_cache_dtype(self, name: str) -> Optional[str]:
-        """KV-cache storage dtype name for ``name``, or None."""
-        return kv_cache_dtype(self.stats(name))
 
     def snapshot(self) -> Dict[str, int]:
         return {name: max(0, int(p())) for name, p in self._probes.items()}

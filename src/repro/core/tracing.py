@@ -36,15 +36,45 @@ Timestamp contract: every span/event/token time is ``time.monotonic()``
 components of one trace are directly comparable. The simulator records
 sim-time traces instead; a trace is internally consistent, never mix the
 two bases within one tracer.
+
+Serving spans on the profiler's clock: ``span(name, trace=None, lane=...,
+**counts)`` is the one way the serving path times a stretch of its own
+work. It opens a ``jax.profiler.TraceAnnotation`` (a ``TraceMe``), so
+while a profiler session runs the span lands on the device trace's host
+plane, on the clock of the device ops, with its counts as event stats;
+with a request ``Trace`` it also records the same interval into that
+trace (the ``--trace-out`` export). Span names start with ``serve.``:
+
+- ``serve.router.place`` — placement (Algorithm 1) and the enqueue;
+- ``serve.engine.lock_wait`` — ``submit`` waiting for the engine lock,
+  which ``step()`` holds for the whole step;
+- ``serve.engine.step`` — one engine step (count ``engine``), with its
+  phases ``serve.engine.grow`` (``preempted``), ``serve.engine.admit``
+  (``admitted``), ``serve.engine.spec``, ``serve.engine.prefill`` (one
+  prefill chunk or whole prompt, dispatch and token read: ``tokens``,
+  ``padded``, ``offset``) and ``serve.engine.decode`` (the batched decode
+  dispatch: ``rows`` swept, ``live`` rows decoding, ``pages_walked`` by
+  the decode kernel, ``pages_live`` of the live rows);
+- ``serve.engine.device_wait`` — a blocking read of a device result;
+- ``serve.loop.resolve`` (``finished``), ``serve.loop.idle`` — the engine
+  loop handing finished sequences to their waiters, and its idle wait;
+- ``serve.gc`` — a Python garbage collection (``generation``,
+  ``collected``), from the hook ``install_gc_spans`` puts in
+  ``gc.callbacks``.
+
+With no profiler session a span costs one ``TraceMe`` enter and exit, and
+its counts are never formatted.
 """
 from __future__ import annotations
 
+import gc
 import json
 import threading
 import time
 from collections import deque
-from contextlib import contextmanager
 from typing import Deque, Dict, List, Optional
+
+from jax.profiler import TraceAnnotation
 
 trace_now = time.monotonic
 
@@ -75,14 +105,6 @@ class Trace:
         with self._lock:
             self.spans.append((name, lane, t0, t1, attrs))
 
-    @contextmanager
-    def span(self, name: str, lane: str = "router", **attrs):
-        t0 = trace_now()
-        try:
-            yield self
-        finally:
-            self.add_span(name, t0, trace_now(), lane=lane, **attrs)
-
     def event(self, name: str, lane: str = "router", t: Optional[float] = None, **attrs) -> None:
         with self._lock:
             self.events.append((name, lane, trace_now() if t is None else t, attrs))
@@ -93,33 +115,7 @@ class Trace:
         with self._lock:
             self.tokens.setdefault(lane, []).extend(times)
 
-    # -- derived / export ------------------------------------------------------
-    def lanes(self) -> List[str]:
-        with self._lock:
-            seen = dict.fromkeys(
-                [lane for _, lane, *_ in self.spans]
-                + [lane for _, lane, *_ in self.events]
-                + list(self.tokens)
-            )
-        return list(seen)
-
-    def ttft_s(self, lane: Optional[str] = None) -> Optional[float]:
-        """First-token latency from trace start for ``lane`` (earliest lane
-        with tokens when None) — None until a token lands."""
-        with self._lock:
-            pools = [self.tokens[lane]] if lane else list(self.tokens.values())
-        firsts = [ts[0] for ts in pools if ts]
-        return min(firsts) - self.t0 if firsts else None
-
-    def itl_s(self, lane: Optional[str] = None) -> List[float]:
-        """Inter-token gaps for ``lane`` (all lanes when None)."""
-        with self._lock:
-            pools = [self.tokens.get(lane, [])] if lane else list(self.tokens.values())
-        out: List[float] = []
-        for ts in pools:
-            out.extend(b - a for a, b in zip(ts, ts[1:]))
-        return out
-
+    # -- export ----------------------------------------------------------------
     def to_dict(self) -> dict:
         with self._lock:
             return {
@@ -243,6 +239,61 @@ class Tracer:
         with open(path, "w") as f:
             json.dump(self.chrome_trace(), f)
         return path
+
+
+class span:
+    """A serving span: ``with span("serve.engine.decode", rows=8) as sp:``
+    times the block as a profiler ``TraceAnnotation`` named ``name`` and,
+    when ``trace`` is a request ``Trace``, records the same interval into it
+    on ``lane``. ``counts`` become the annotation's stats (formatted only
+    while a profiler session runs) and the trace span's attrs;
+    ``sp.set(**counts)`` adds counts known only at the end of the block."""
+
+    __slots__ = ("name", "trace", "lane", "counts", "_ann", "_t0")
+
+    def __init__(self, name: str, trace: Optional[Trace] = None, lane: str = "router",
+                 **counts):
+        self.name, self.trace, self.lane, self.counts = name, trace, lane, counts
+
+    def __enter__(self) -> "span":
+        self._ann = TraceAnnotation(self.name, **self.counts)   # starts the clock
+        if self.trace is not None:
+            self._t0 = trace_now()
+        return self
+
+    def set(self, **counts) -> None:
+        self._ann.set_metadata(**counts)
+        if self.trace is not None:
+            self.counts.update(counts)
+
+    def __exit__(self, *exc) -> None:
+        if self.trace is not None:
+            self.trace.add_span(self.name, self._t0, trace_now(), lane=self.lane,
+                                **self.counts)
+        self._ann.__exit__(None, None, None)
+
+
+_gc_ann: Optional[TraceAnnotation] = None
+
+
+def _gc_span(phase: str, info: dict) -> None:
+    """``gc.callbacks`` hook: a ``serve.gc`` span around each collection
+    that starts while a profiler session runs. Collections never nest, and
+    each starts and stops on one thread."""
+    global _gc_ann
+    if phase == "start":
+        if TraceAnnotation.is_enabled():
+            _gc_ann = TraceAnnotation("serve.gc", generation=info["generation"])
+    elif _gc_ann is not None:
+        ann, _gc_ann = _gc_ann, None
+        ann.set_metadata(collected=info["collected"])
+        ann.__exit__(None, None, None)
+
+
+def install_gc_spans() -> None:
+    """Put the ``serve.gc`` hook in ``gc.callbacks``, once per process."""
+    if _gc_span not in gc.callbacks:
+        gc.callbacks.append(_gc_span)
 
 
 NULL_TRACER = Tracer(enabled=False)

@@ -96,5 +96,6 @@ def decode_attention_grouped(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KV, G, hd), q.dtype),
+        name="decode_attention_grouped",
         interpret=interpret,
     )(lengths, q, k, v)

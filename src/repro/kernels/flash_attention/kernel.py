@@ -96,5 +96,6 @@ def flash_attention_bhsd(
             pltpu.VMEM((bq, 1), jnp.float32),    # running max
             pltpu.VMEM((bq, 1), jnp.float32),    # normalizer
         ],
+        name="flash_attention_bhsd",
         interpret=interpret,
     )(q, k, v)

@@ -120,6 +120,7 @@ def mlstm_chunkwise_bh(
             pltpu.VMEM((DH, 1), jnp.float32),    # n
             pltpu.VMEM((1, 1), jnp.float32),     # m
         ],
+        name="mlstm_chunkwise_bh",
         interpret=interpret,
     )(q, k, v, i, lf)
     return h, Cf, nf, mf

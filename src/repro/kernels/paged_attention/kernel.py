@@ -169,6 +169,7 @@ def paged_attention_grouped(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KV, G, hd), q.dtype),
+        name="paged_attention_grouped",
         interpret=interpret,
     )(*scalars, *operands)
 
@@ -241,6 +242,7 @@ def paged_prefill_write_grouped(
         ],
         # operand indices count the scalar-prefetch arg: tab=0, k=1, v=2
         input_output_aliases={3: 0, 4: 1},
+        name="paged_prefill_write_grouped",
         interpret=interpret,
     )(tab_row, k, v, pool_k, pool_v)
 
@@ -310,5 +312,6 @@ def paged_prefill_write_grouped_quant(
         ],
         # operand indices count the scalar-prefetch arg: tab=0, k=1, v=2
         input_output_aliases={3: 0, 4: 1, 5: 2, 6: 3},
+        name="paged_prefill_write_grouped_quant",
         interpret=interpret,
     )(tab_row, k, v, pool_k, pool_v, pool_ks, pool_vs)

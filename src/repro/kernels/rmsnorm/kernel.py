@@ -31,5 +31,6 @@ def rmsnorm_2d(x: jax.Array, w: jax.Array, eps: float = 1e-6,
         ],
         out_specs=pl.BlockSpec((block_rows, D), lambda r: (r, 0)),
         out_shape=jax.ShapeDtypeStruct((R, D), x.dtype),
+        name="rmsnorm_2d",
         interpret=interpret,
     )(x, w)

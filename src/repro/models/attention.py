@@ -479,7 +479,8 @@ def self_attention(
         # truly paged prefill: K/V scatter straight through the block table
         # into the page pool — no dense per-length staging cache exists.
         assert cache is not None
-        new_cache = paged_write_prompt(cfg, cache, k, v, cache_index.tab_row)
+        with jax.named_scope("kv_write"):
+            new_cache = paged_write_prompt(cfg, cache, k, v, cache_index.tab_row)
         o = chunked_attention(cfg, q, k, v, pos_t, pos_t, causal=causal)
     elif mode == "prefill" and isinstance(cache_index, PagedChunkPrefillIndex):
         # chunked paged prefill: scatter this chunk at its page-aligned
@@ -488,14 +489,16 @@ def self_attention(
         from repro.kernels.paged_attention import ops as pa_ops
 
         assert cache is not None
-        new_cache = paged_write_prompt(
-            cfg, cache, k, v, cache_index.tab_row, offset=cache_index.offset
-        )
-        ck, cv = pa_ops.paged_gather_context(
-            new_cache["k"], new_cache["v"], cache_index.tab_row,
-            pool_ks=new_cache.get("k_scale"), pool_vs=new_cache.get("v_scale"),
-        )
-        o = context_attention(cfg, q, ck.astype(x.dtype), cv.astype(x.dtype), pos_t)
+        with jax.named_scope("kv_write"):
+            new_cache = paged_write_prompt(
+                cfg, cache, k, v, cache_index.tab_row, offset=cache_index.offset
+            )
+        with jax.named_scope("paged_attn"):
+            ck, cv = pa_ops.paged_gather_context(
+                new_cache["k"], new_cache["v"], cache_index.tab_row,
+                pool_ks=new_cache.get("k_scale"), pool_vs=new_cache.get("v_scale"),
+            )
+            o = context_attention(cfg, q, ck.astype(x.dtype), cv.astype(x.dtype), pos_t)
     elif mode == "prefill" and isinstance(cache_index, PagedVerifyIndex):
         # speculative verify: scatter the k+1 verify tokens' K/V at an
         # arbitrary (mid-page) offset, then attend over the gathered context
@@ -505,51 +508,58 @@ def self_attention(
 
         assert cache is not None
         new_cache = dict(cache)
-        if "k_scale" in cache:
-            (
-                new_cache["k"], new_cache["v"],
-                new_cache["k_scale"], new_cache["v_scale"],
-            ) = pa_ops.paged_verify_write_quant(
-                cache["k"], cache["v"], cache["k_scale"], cache["v_scale"],
-                k, v, cache_index.tab_row, cache_index.offset,
+        with jax.named_scope("kv_write"):
+            if "k_scale" in cache:
+                (
+                    new_cache["k"], new_cache["v"],
+                    new_cache["k_scale"], new_cache["v_scale"],
+                ) = pa_ops.paged_verify_write_quant(
+                    cache["k"], cache["v"], cache["k_scale"], cache["v_scale"],
+                    k, v, cache_index.tab_row, cache_index.offset,
+                )
+            else:
+                new_cache["k"], new_cache["v"] = pa_ops.paged_verify_write(
+                    cache["k"], cache["v"], k, v, cache_index.tab_row, cache_index.offset
+                )
+        with jax.named_scope("paged_attn"):
+            ck, cv = pa_ops.paged_gather_context(
+                new_cache["k"], new_cache["v"], cache_index.tab_row,
+                pool_ks=new_cache.get("k_scale"), pool_vs=new_cache.get("v_scale"),
             )
-        else:
-            new_cache["k"], new_cache["v"] = pa_ops.paged_verify_write(
-                cache["k"], cache["v"], k, v, cache_index.tab_row, cache_index.offset
-            )
-        ck, cv = pa_ops.paged_gather_context(
-            new_cache["k"], new_cache["v"], cache_index.tab_row,
-            pool_ks=new_cache.get("k_scale"), pool_vs=new_cache.get("v_scale"),
-        )
-        o = context_attention(cfg, q, ck.astype(x.dtype), cv.astype(x.dtype), pos_t)
+            o = context_attention(cfg, q, ck.astype(x.dtype), cv.astype(x.dtype), pos_t)
     elif mode == "prefill" and isinstance(cache_index, ChunkPrefillIndex):
         # chunked dense prefill: write this chunk into the slot's stripe at
         # ``offset`` and attend over the whole stripe by absolute position.
         assert cache is not None
-        new_cache = cache_kv(cfg, cache, k, v, cache_index.offset)
+        with jax.named_scope("kv_write"):
+            new_cache = cache_kv(cfg, cache, k, v, cache_index.offset)
         ck, cv = read_kv(cfg, new_cache, x.dtype)
         o = context_attention(cfg, q, ck, cv, pos_t)
     elif mode == "prefill":
         assert cache is not None
-        new_cache = cache_kv(cfg, cache, k, v, 0 if cache_index is None else cache_index)
+        with jax.named_scope("kv_write"):
+            new_cache = cache_kv(cfg, cache, k, v, 0 if cache_index is None else cache_index)
         o = chunked_attention(cfg, q, k, v, pos_t, pos_t, causal=causal)
     elif mode == "decode" and isinstance(cache_index, PagedIndex):
         assert cache is not None and S == 1
-        new_cache = paged_cache_kv(cfg, cache, k, v, cache_index)
+        with jax.named_scope("kv_write"):
+            new_cache = paged_cache_kv(cfg, cache, k, v, cache_index)
         from repro.kernels.paged_attention import ops as pa_ops
 
-        o = pa_ops.paged_attention(
-            q, new_cache["k"], new_cache["v"],
-            cache_index.block_tab, cache_index.lengths + 1,
-            use_pallas=cfg.use_pallas,
-            softcap=cfg.logit_softcap,
-            pool_ks=new_cache.get("k_scale"),
-            pool_vs=new_cache.get("v_scale"),
-            l2_tab=cache_index.l2,
-        )
+        with jax.named_scope("paged_attn"):
+            o = pa_ops.paged_attention(
+                q, new_cache["k"], new_cache["v"],
+                cache_index.block_tab, cache_index.lengths + 1,
+                use_pallas=cfg.use_pallas,
+                softcap=cfg.logit_softcap,
+                pool_ks=new_cache.get("k_scale"),
+                pool_vs=new_cache.get("v_scale"),
+                l2_tab=cache_index.l2,
+            )
     elif mode == "decode":
         assert cache is not None and cache_index is not None
-        new_cache = cache_kv(cfg, cache, k, v, cache_index)
+        with jax.named_scope("kv_write"):
+            new_cache = cache_kv(cfg, cache, k, v, cache_index)
         if cfg.kv_quant:
             o = decode_attention_quant(cfg, q, new_cache, cache_len=cache_index + S)
         else:

@@ -216,6 +216,7 @@ def norm_defs(cfg: ModelConfig, d: int = 0) -> dict:
     return {"w": ParamDef((d,), (NULL,), "ones")}
 
 
+@jax.named_scope("norm")
 def apply_norm(cfg: ModelConfig, p: Mapping, x: jax.Array) -> jax.Array:
     if cfg.norm == "layernorm":
         return layernorm(x, p["w"], p["b"])
@@ -248,6 +249,7 @@ def embed_defs(cfg: ModelConfig) -> dict:
     return d
 
 
+@jax.named_scope("embed")
 def embed_tokens(cfg: ModelConfig, p: Mapping, tokens: jax.Array) -> jax.Array:
     x = p["embedding"][tokens]
     return x.astype(cfg.compute_dtype)

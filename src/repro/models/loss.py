@@ -86,14 +86,16 @@ def next_tokens_all(cfg: ModelConfig, ctx, params: Mapping, hidden: jax.Array) -
     each verified position in one shot. Argmax is monotone under the tanh
     softcap, so (matching ``next_tokens``) the cap is skipped — ids are
     identical either way and the (B, S, V) logits slice stays transient."""
-    w = unembed_weight(cfg, params)
-    logits = qeinsum("bsd,dv->bsv", hidden, w).astype(jnp.float32)
-    if ctx is not None:
-        vocab_ax = ctx.tp_axis if logits.shape[-1] % ctx.tp_size == 0 else None
-        logits = jax.lax.with_sharding_constraint(
-            logits, jax.sharding.NamedSharding(ctx.mesh, P(None, None, vocab_ax))
-        )
-    return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    with jax.named_scope("head"):
+        w = unembed_weight(cfg, params)
+        logits = qeinsum("bsd,dv->bsv", hidden, w).astype(jnp.float32)
+        if ctx is not None:
+            vocab_ax = ctx.tp_axis if logits.shape[-1] % ctx.tp_size == 0 else None
+            logits = jax.lax.with_sharding_constraint(
+                logits, jax.sharding.NamedSharding(ctx.mesh, P(None, None, vocab_ax))
+            )
+    with jax.named_scope("sample"):
+        return jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
 
 def next_tokens(cfg: ModelConfig, ctx, params: Mapping, hidden_last: jax.Array) -> jax.Array:
@@ -102,12 +104,14 @@ def next_tokens(cfg: ModelConfig, ctx, params: Mapping, hidden_last: jax.Array) 
     Argmax over the TP-sharded vocab dim stays a cheap sharded reduce —
     serve_step outputs token ids, never full logits.
     """
-    w = unembed_weight(cfg, params)
-    x = hidden_last[:, -1, :]
-    logits = qeinsum("bd,dv->bv", x, w).astype(jnp.float32)
-    if ctx is not None:
-        vocab_ax = ctx.tp_axis if logits.shape[-1] % ctx.tp_size == 0 else None
-        logits = jax.lax.with_sharding_constraint(
-            logits, jax.sharding.NamedSharding(ctx.mesh, P(None, vocab_ax))
-        )
-    return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    with jax.named_scope("head"):
+        w = unembed_weight(cfg, params)
+        x = hidden_last[:, -1, :]
+        logits = qeinsum("bd,dv->bv", x, w).astype(jnp.float32)
+        if ctx is not None:
+            vocab_ax = ctx.tp_axis if logits.shape[-1] % ctx.tp_size == 0 else None
+            logits = jax.lax.with_sharding_constraint(
+                logits, jax.sharding.NamedSharding(ctx.mesh, P(None, vocab_ax))
+            )
+    with jax.named_scope("sample"):
+        return jnp.argmax(logits, axis=-1).astype(jnp.int32)

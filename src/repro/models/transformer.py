@@ -249,9 +249,10 @@ def _superblock(
         h = apply_norm(cfg, p[f"l{i}_norm"], x)
         c_in = cache_sb.get(f"l{i}_mixer") if cache_sb is not None else None
         if kind == "attn":
-            h, c_out = attn.self_attention(
-                cfg, p[f"l{i}_mixer"], h, positions, mode, c_in, cache_index, causal=causal
-            )
+            with jax.named_scope("attn"):
+                h, c_out = attn.self_attention(
+                    cfg, p[f"l{i}_mixer"], h, positions, mode, c_in, cache_index, causal=causal
+                )
         elif chunk_pf and chunk_sb is not None:
             s_in = chunk_sb[f"l{i}_mixer"]
             h, s_out = recurrent[kind](cfg, p[f"l{i}_mixer"], h, mode, s_in, valid=valid)
@@ -285,11 +286,12 @@ def _superblock(
             x = x + attn.cross_attention(cfg, p[f"l{i}_cross"], h, kv)
         if cfg.d_ff > 0:
             h = apply_norm(cfg, p[f"l{i}_ffn_norm"], x)
-            if cfg.layer_has_moe(i):
-                h, a = moe_ffn(cfg, ctx, p[f"l{i}_ffn"], h, valid=valid)
-                aux = aux + a
-            else:
-                h = mlp(cfg, p[f"l{i}_ffn"], h)
+            with jax.named_scope("mlp"):
+                if cfg.layer_has_moe(i):
+                    h, a = moe_ffn(cfg, ctx, p[f"l{i}_ffn"], h, valid=valid)
+                    aux = aux + a
+                else:
+                    h = mlp(cfg, p[f"l{i}_ffn"], h)
             x = x + h
         x = _constrain(ctx, x, cfg.seq_shard_activations)
     return (

@@ -135,6 +135,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.tracing import span
 from repro.models import get_model
 from repro.serving.paging import (
     NULL_PAGE,
@@ -222,6 +223,9 @@ class Sequence:
     # step — so latency histograms exist even without a tracer attached.
     submit_t: float = 0.0
     token_times: List[float] = field(default_factory=list)
+    # first admission into a slot (chunked or whole prefill), kept across
+    # preemption: admit_t - submit_t is the time spent in the engine's queue
+    admit_t: Optional[float] = None
     # lifecycle trace context (core/tracing.Trace) carried from the router
     # through EngineLoop.submit; None = untraced (zero-cost path)
     trace: Optional[object] = field(default=None, repr=False, compare=False)
@@ -251,12 +255,18 @@ class _EngineBase:
     ``_chunk_carry`` / ``_stamp``) and the reentrant ``lock`` (see the
     module docstring for the thread-safety contract)."""
 
+    name = "engine"      # labels this engine's step spans; EngineLoop sets it
+
     def free_slots(self) -> int:
         return sum(1 for s in self.slot_seq if s is None)
 
     def submit(self, prompt: List[int], trace=None) -> int:
+        # stamped before the wait: step() holds the lock for a whole step
+        submit_t = time.monotonic()
+        waiting = span("serve.engine.lock_wait").__enter__()
         with self.lock:
-            seq = Sequence(self._sid, list(prompt), submit_t=time.monotonic(), trace=trace)
+            waiting.__exit__()
+            seq = Sequence(self._sid, list(prompt), submit_t=submit_t, trace=trace)
             self._sid += 1
             self.waiting.append(seq)
             if trace is not None:
@@ -379,6 +389,8 @@ class _EngineBase:
         cursor lands on the sequence's first exclusively-owned page."""
         self.slot_seq[slot] = seq
         self.slot_len[slot] = start
+        if seq.admit_t is None:
+            seq.admit_t = time.monotonic()
         self._chunking[slot] = True
         self._chunk_pos[slot] = start
         self._chunk_ctx[slot] = seq.context_tokens()
@@ -442,24 +454,24 @@ class _EngineBase:
         pos = int(self._chunk_pos[slot])
         piece = ctx[pos : pos + self._chunk_unit]
         toks, n, _, fresh = self._pad_context(piece, cap=self._chunk_unit)
-        tr = seq.trace
-        tr0 = time.monotonic() if tr is not None else 0.0
-        t0 = time.perf_counter()
-        nxt = self._run_chunk_device(slot, toks, pos, n)
-        if fresh:
-            jax.block_until_ready(nxt)
-            self._note_compile(time.perf_counter() - t0)
-        if tr is not None:
-            tr.add_span("prefill_chunk", tr0, time.monotonic(), lane=seq.lane,
-                        offset=pos, tokens=n, fresh_compile=fresh)
-        new_pos = pos + n
-        self._chunk_pos[slot] = new_pos
-        self.slot_len[slot] = new_pos
-        if new_pos < len(ctx):
-            return                                    # mid-prefill: token is garbage
-        self.cache = self._install_carry(self.cache, self._chunk_carry[slot], jnp.asarray(slot))
+        with span("serve.engine.prefill", seq.trace, lane=seq.lane, tokens=n,
+                  padded=len(toks), offset=pos, fresh_compile=fresh):
+            t0 = time.perf_counter()
+            nxt = self._run_chunk_device(slot, toks, pos, n)
+            if fresh:
+                with span("serve.engine.device_wait"):
+                    jax.block_until_ready(nxt)
+                self._note_compile(time.perf_counter() - t0)
+            new_pos = pos + n
+            self._chunk_pos[slot] = new_pos
+            self.slot_len[slot] = new_pos
+            if new_pos < len(ctx):
+                return                                # mid-prefill: token is garbage
+            self.cache = self._install_carry(
+                self.cache, self._chunk_carry[slot], jnp.asarray(slot))
+            with span("serve.engine.device_wait"):
+                tok = int(nxt)
         self._clear_chunk_slot(slot)              # PREFILLING -> decoding
-        tok = int(nxt)
         self._last[slot] = tok
         seq.out.append(tok)
         seq.token_times.append(time.monotonic())  # the prefill-emitted token
@@ -676,6 +688,7 @@ class InferenceEngine(_EngineBase):
         model, ctx = self.model, self.ctx
         B, L = self.ecfg.max_slots, self.ecfg.max_len
 
+        @jax.named_scope("prefill")
         def prefill_slot(params, cache, tokens, slot, n_valid):
             """Prefill a single slot with a right-padded prompt of length L_p;
             positions >= n_valid are bucket padding, masked out of every
@@ -694,12 +707,14 @@ class InferenceEngine(_EngineBase):
             cache = jax.tree.map(write, cache, mini)
             return next_tok[0], cache
 
+        @jax.named_scope("decode_all")
         def decode_all(params, cache, last_tokens, lens):
             """One decode step for every slot; per-slot lengths drive the
             cache writes, masks and positions."""
             batch = {"token": last_tokens[:, None], "cache_index": jnp.max(lens), "lengths": lens}
             return model.decode(ctx, params, cache, batch)
 
+        @jax.named_scope("prefill_chunk")
         def prefill_chunk_slot(params, cache, tokens, slot, offset, n_valid, carry):
             """One chunked-prefill step against the slot's stripe: slice the
             mini cache out, run the resumable chunk (K/V written at
@@ -719,6 +734,7 @@ class InferenceEngine(_EngineBase):
 
             return nxt[0], jax.tree.map(write, cache, mini), carry
 
+        @jax.named_scope("verify")
         def verify_slot(params, cache, tokens, slot, offset):
             """Speculative verify against the slot's stripe: slice the mini
             cache out, write all k+1 verify tokens at ``offset`` and read
@@ -835,27 +851,29 @@ class InferenceEngine(_EngineBase):
                 break                        # over budget: stays queued
             seq = self.waiting.popleft()
             toks, n, _, fresh = self._pad_context(seq.prompt)
-            tr = seq.trace
-            tr0 = time.monotonic() if tr is not None else 0.0
-            t0 = time.perf_counter()
-            nxt, self.cache = self._prefill(
-                self.params, self.cache, jnp.asarray(toks), jnp.asarray(i), jnp.asarray(n)
-            )
-            if fresh:
-                jax.block_until_ready(nxt)
-                self._note_compile(time.perf_counter() - t0)
-            if tr is not None:
-                tr.add_span("prefill", tr0, time.monotonic(), lane=seq.lane,
-                            slot=i, tokens=n, fresh_compile=fresh)
+            with span("serve.engine.prefill", seq.trace, lane=seq.lane, tokens=n,
+                      padded=len(toks), offset=0, slot=i, fresh_compile=fresh):
+                t0 = time.perf_counter()
+                nxt, self.cache = self._prefill(
+                    self.params, self.cache, jnp.asarray(toks), jnp.asarray(i), jnp.asarray(n)
+                )
+                if fresh:
+                    with span("serve.engine.device_wait"):
+                        jax.block_until_ready(nxt)
+                    self._note_compile(time.perf_counter() - t0)
+                with span("serve.engine.device_wait"):
+                    nxt = int(nxt)
             spent += Lp
             admitted = True
             self.slot_seq[i] = seq
             self.slot_len[i] = n
-            self._last[i] = int(nxt)
-            seq.out.append(int(nxt))
+            if seq.admit_t is None:
+                seq.admit_t = time.monotonic()
+            self._last[i] = nxt
+            seq.out.append(nxt)
             seq.token_times.append(time.monotonic())
             self.tokens_emitted += 1
-            if self._stop_hit(seq, int(nxt), int(self.slot_len[i])):
+            if self._stop_hit(seq, nxt, int(self.slot_len[i])):
                 # the prefill-emitted token can already cross a stop
                 # condition (max_new_tokens=1, or greedy EOS on prompt)
                 seq.done = True
@@ -891,7 +909,9 @@ class InferenceEngine(_EngineBase):
                 jnp.asarray(L),
             )
             spent += k_eff
-            _, done = self._accept_verified(slot, seq, proposal, np.asarray(toks), k_eff)
+            with span("serve.engine.device_wait"):
+                toks = np.asarray(toks)
+            _, done = self._accept_verified(slot, seq, proposal, toks, k_eff)
             sped.append(slot)
             if done:
                 seq.done = True
@@ -910,14 +930,17 @@ class InferenceEngine(_EngineBase):
         new write-head is idempotent with the next step's decode write
         (same token, same position), so only the host bookkeeping skips
         them."""
-        with self.lock:
+        with self.lock, span("serve.engine.step", engine=self.name):
             budget = self.step_budget
             self.spec_runs = []
             spent = sum(
                 1 for i, s in enumerate(self.slot_seq)
                 if s is not None and not self._chunking[i]
             )
-            spent = self._admit(spent, budget)
+            with span("serve.engine.admit") as sp:
+                queued = len(self.waiting)
+                spent = self._admit(spent, budget)
+                sp.set(admitted=queued - len(self.waiting))
             if self._chunk_tokens:
                 spent = self._run_chunks(spent, budget)
             active = [
@@ -925,15 +948,18 @@ class InferenceEngine(_EngineBase):
                 if self.slot_seq[i] is not None and not self._chunking[i]
             ]
             if self._spec_tokens and active:
-                sped, spent = self._spec_phase(active, spent, budget)
+                with span("serve.engine.spec"):
+                    sped, spent = self._spec_phase(active, spent, budget)
                 active = [i for i in active if i not in set(sped)]
             finished, self._just_finished = self._just_finished, []
             if active:
-                lens = jnp.asarray(self.slot_len)
-                nxt, self.cache = self._decode(
-                    self.params, self.cache, jnp.asarray(self._last), lens
-                )
-                nxt = np.asarray(nxt)
+                with span("serve.engine.decode", rows=self.ecfg.max_slots, live=len(active)):
+                    lens = jnp.asarray(self.slot_len)
+                    nxt, self.cache = self._decode(
+                        self.params, self.cache, jnp.asarray(self._last), lens
+                    )
+                with span("serve.engine.device_wait"):
+                    nxt = np.asarray(nxt)
                 tok_t = time.monotonic()      # one stamp per batched decode step
                 for i in active:
                     seq = self.slot_seq[i]
@@ -1103,6 +1129,7 @@ class PagedInferenceEngine(_EngineBase):
     def _build(self):
         model, ctx, cfg = self.model, self.ctx, self.cfg
 
+        @jax.named_scope("prefill")
         def prefill_paged(params, cache, tokens, tab_row, slot, n_valid):
             """Prefill one bucket-padded sequence through the model's paged
             path: attention K/V scatter through the block-table row inside
@@ -1118,6 +1145,7 @@ class PagedInferenceEngine(_EngineBase):
             return next_tok[0], cache
 
         if self.chain is not None:
+            @jax.named_scope("decode_all")
             def decode_all(params, cache, last_tokens, lens, tab, l2):
                 # chained decode: tab is the (B, W1) first-level table, l2
                 # the shared second-level pool — the kernel resolves pages
@@ -1128,6 +1156,7 @@ class PagedInferenceEngine(_EngineBase):
                 }
                 return model.decode(ctx, params, cache, batch)
         else:
+            @jax.named_scope("decode_all")
             def decode_all(params, cache, last_tokens, lens, tab):
                 batch = {"token": last_tokens[:, None], "lengths": lens, "block_tab": tab}
                 return model.decode(ctx, params, cache, batch)
@@ -1151,6 +1180,7 @@ class PagedInferenceEngine(_EngineBase):
                     out_blocks[key] = jax.tree.map(copy_slot, cache["blocks"][key])
             return {"blocks": out_blocks}
 
+        @jax.named_scope("prefill_chunk")
         def prefill_chunk_paged(params, cache, tokens, tab_row, slot, offset, n_valid, carry):
             """One chunked-prefill step straight into the page pool: the
             chunk's K/V scatters through the row at its page-aligned offset
@@ -1166,6 +1196,7 @@ class PagedInferenceEngine(_EngineBase):
             nxt, cache, carry = model.prefill_chunk_paged(ctx, params, batch, cache, carry)
             return nxt[0], cache, carry
 
+        @jax.named_scope("verify")
         def verify_paged(params, cache, tokens, tab_row, offset):
             """Speculative verify straight against the page pool: the k+1
             verify tokens scatter through the row at the (mid-page)
@@ -1278,6 +1309,18 @@ class PagedInferenceEngine(_EngineBase):
             *(jnp.asarray(t) for t in tabs),
         )
 
+    def _decode_counts(self, active: List[int]) -> Dict[str, int]:
+        """What one batched decode sweeps: every slot's row (``rows``), of
+        which ``live`` decode; the kernel's grid walks the whole row width
+        of each — flat or chained alike — (``pages_walked``, per KV head),
+        while the live rows hold ``pages_live`` pages of the tokens they
+        attend over (cache plus the token being written)."""
+        ps = self.pcfg.page_size
+        live = (self.slot_len[active] + ps) // ps       # ceil((len + 1) / ps)
+        B = self.pcfg.max_slots
+        return {"rows": B, "live": len(active), "pages_walked": B * self._row_width,
+                "pages_live": int(live.sum())}
+
     def _prewarm_shape(self, Lp: int, slot: int) -> None:
         """Compile a paged prefill at shape ``Lp`` through an all-null
         block-table row: K/V writes land on the reserved null page (garbage
@@ -1341,30 +1384,32 @@ class PagedInferenceEngine(_EngineBase):
         self.tables[slot] = table
         self._sync_row(slot)
         toks, n, _, fresh = self._pad_context(ctx_toks)
-        tr = seq.trace
-        tr0 = time.monotonic() if tr is not None else 0.0
-        t0 = time.perf_counter()
-        nxt, self.cache = self._prefill(
-            self.params,
-            self.cache,
-            jnp.asarray(toks),
-            jnp.asarray(self.block_tab[slot]),
-            jnp.asarray(slot),
-            jnp.asarray(n),
-        )
-        if fresh:
-            jax.block_until_ready(nxt)
-            self._note_compile(time.perf_counter() - t0)
-        if tr is not None:
-            tr.add_span("prefill", tr0, time.monotonic(), lane=seq.lane,
-                        slot=slot, tokens=n, fresh_compile=fresh,
-                        resume=seq.preemptions)
+        with span("serve.engine.prefill", seq.trace, lane=seq.lane, tokens=n,
+                  padded=len(toks), offset=0, slot=slot, fresh_compile=fresh,
+                  resume=seq.preemptions):
+            t0 = time.perf_counter()
+            nxt, self.cache = self._prefill(
+                self.params,
+                self.cache,
+                jnp.asarray(toks),
+                jnp.asarray(self.block_tab[slot]),
+                jnp.asarray(slot),
+                jnp.asarray(n),
+            )
+            if fresh:
+                with span("serve.engine.device_wait"):
+                    jax.block_until_ready(nxt)
+                self._note_compile(time.perf_counter() - t0)
+            with span("serve.engine.device_wait"):
+                nxt = int(nxt)
         self.slot_seq[slot] = seq
         self.slot_len[slot] = n
-        self._last[slot] = int(nxt)
+        if seq.admit_t is None:
+            seq.admit_t = time.monotonic()
+        self._last[slot] = nxt
         self._stamp[slot] = self._stamp_next
         self._stamp_next += 1
-        return int(nxt)
+        return nxt
 
     def _reserve_pages(self, n: int, seq: Optional[Sequence] = None) -> bool:
         """Make ``n`` pages allocatable, reclaiming cold prefix-cache leaves
@@ -1572,7 +1617,9 @@ class PagedInferenceEngine(_EngineBase):
                 jnp.asarray(L),
             )
             spent += k_eff
-            m, done = self._accept_verified(slot, seq, proposal, np.asarray(toks), k_eff)
+            with span("serve.engine.device_wait"):
+                toks = np.asarray(toks)
+            m, done = self._accept_verified(slot, seq, proposal, toks, k_eff)
             keep = max(n0, PageTable.pages_needed(L + m, ps))
             if table.trim(keep, self.allocator):
                 self._sync_row(slot)
@@ -1600,16 +1647,22 @@ class PagedInferenceEngine(_EngineBase):
         writes their pending token at the new write-head — idempotent with
         the next step's decode write when that page is allocated, absorbed
         by the null page when it is not."""
-        with self.lock:
+        with self.lock, span("serve.engine.step", engine=self.name):
             budget = self.step_budget
             self.spec_runs = []
             occupied = [i for i in range(self.pcfg.max_slots) if self.slot_seq[i] is not None]
-            self._ensure_growth(occupied)
+            with span("serve.engine.grow") as sp:
+                preempted = self.preemptions
+                self._ensure_growth(occupied)
+                sp.set(preempted=self.preemptions - preempted)
             spent = sum(
                 1 for i, s in enumerate(self.slot_seq)
                 if s is not None and not self._chunking[i]
             )
-            spent = self._admit(spent, budget)
+            with span("serve.engine.admit") as sp:
+                queued = len(self.waiting)
+                spent = self._admit(spent, budget)
+                sp.set(admitted=queued - len(self.waiting))
             if self._chunk_tokens or self.prefix_cache is not None:
                 spent = self._run_chunks(spent, budget)
             active = [
@@ -1618,12 +1671,15 @@ class PagedInferenceEngine(_EngineBase):
             ]
             self.peak_active = max(self.peak_active, len(active))
             if self._spec_tokens and active:
-                sped, spent = self._spec_phase(active, spent, budget)
+                with span("serve.engine.spec"):
+                    sped, spent = self._spec_phase(active, spent, budget)
                 active = [i for i in active if i not in set(sped)]
             finished, self._just_finished = self._just_finished, []
             if active:
-                nxt, self.cache = self._decode(*self._decode_args())
-                nxt = np.asarray(nxt)
+                with span("serve.engine.decode", **self._decode_counts(active)):
+                    nxt, self.cache = self._decode(*self._decode_args())
+                with span("serve.engine.device_wait"):
+                    nxt = np.asarray(nxt)
                 tok_t = time.monotonic()      # one stamp per batched decode step
                 for i in active:
                     seq = self.slot_seq[i]
@@ -1664,8 +1720,9 @@ class PagedInferenceEngine(_EngineBase):
             except OutOfPages:
                 return None
             seq = self.slot_seq[src]
+            now = time.monotonic()
             clone = Sequence(self._sid, list(seq.prompt), out=list(seq.out),
-                             submit_t=time.monotonic(), trace=seq.trace,
+                             submit_t=now, admit_t=now, trace=seq.trace,
                              cached_tokens=seq.cached_tokens)
             if self._cache_nodes[src] is not None:
                 # the clone shares the source's cache-attached pages: it must

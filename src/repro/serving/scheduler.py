@@ -54,7 +54,7 @@ import time
 from typing import Dict, List, Optional
 
 from repro.core.telemetry import MetricsRegistry, default_registry, log_buckets
-from repro.core.tracing import Trace, trace_now
+from repro.core.tracing import Trace, install_gc_spans, span, trace_now
 from repro.serving.engine import Sequence
 
 
@@ -91,6 +91,7 @@ class EngineLoop:
         self.engine = engine
         self.idle_wait_s = idle_wait_s
         self.name = name
+        engine.name = name             # labels the engine's step spans
         self.registry = registry if registry is not None else default_registry()
         self._lock = threading.Lock()
         self._futures: Dict[int, _SeqFuture] = {}    # guarded by: _lock
@@ -121,6 +122,7 @@ class EngineLoop:
             self._stop_flag = False
             t = self._thread = threading.Thread(
                 target=self._run, daemon=True, name="engine-loop")
+        install_gc_spans()
         t.start()
         return self
 
@@ -288,7 +290,8 @@ class EngineLoop:
             if not self._busy():
                 # cleared BEFORE the busy check: a submit landing after the
                 # check sets the event and the wait returns immediately
-                self._work.wait(self.idle_wait_s)
+                with span("serve.loop.idle"):
+                    self._work.wait(self.idle_wait_s)
                 continue
             try:
                 self.step_once()
@@ -298,19 +301,20 @@ class EngineLoop:
                 return
 
     def _resolve(self, seqs: List[Sequence]) -> None:
-        for seq in seqs:
-            self._observe_finished(seq)
-        with self._lock:
+        with span("serve.loop.resolve", finished=len(seqs)):
             for seq in seqs:
-                if seq.sid in self._abandoned:     # waiter timed out and left
-                    self._abandoned.discard(seq.sid)
-                    continue
-                fut = self._futures.get(seq.sid)
-                if fut is None:
-                    self._unclaimed[seq.sid] = seq
-                else:
-                    fut.seq = seq
-                    fut.event.set()
+                self._observe_finished(seq)
+            with self._lock:
+                for seq in seqs:
+                    if seq.sid in self._abandoned:     # waiter timed out and left
+                        self._abandoned.discard(seq.sid)
+                        continue
+                    fut = self._futures.get(seq.sid)
+                    if fut is None:
+                        self._unclaimed[seq.sid] = seq
+                    else:
+                        fut.seq = seq
+                        fut.event.set()
 
     def _observe_finished(self, seq: Sequence) -> None:
         """Per-sequence terminal observability: TTFT / inter-token-latency

@@ -330,8 +330,12 @@ def test_stop_clears_unclaimed_and_abandoned_registries(cfg):
     assert sid in loop._abandoned
     loop.stop()
     assert not loop._unclaimed and not loop._abandoned and not loop._futures
-    loop.start()                                   # restart begins with a clean registry
-    assert not loop._unclaimed and not loop._abandoned
+    # the abandoned sequence is still in the engine: holding the engine lock
+    # keeps the restarted thread from finishing it (and parking it in
+    # ``_unclaimed``) before the registry is read
+    with eng.lock:
+        loop.start()                               # restart begins with a clean registry
+        assert not loop._unclaimed and not loop._abandoned
     loop.stop()
 
 

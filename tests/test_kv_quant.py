@@ -306,15 +306,11 @@ def test_capacity_telemetry_reports_kv_bytes_per_token():
     ratio = snaps["f32"]["kv_bytes_per_token"] / snaps["int8"]["kv_bytes_per_token"]
     assert ratio >= 1.8, ratio
 
-    from repro.core.telemetry import CapacityGauge, kv_bytes_per_token, kv_cache_dtype
+    from repro.core.telemetry import kv_bytes_per_token, kv_cache_dtype
 
     assert kv_bytes_per_token(snaps["int8"]) == snaps["int8"]["kv_bytes_per_token"]
     assert kv_cache_dtype(snaps["int8"]) == "int8"
     assert kv_bytes_per_token({}) is None and kv_cache_dtype(None) is None
-    g = CapacityGauge()
-    g.register_stats("flask", lambda: snaps["int8"])
-    assert g.kv_cache_dtype("flask") == "int8"
-    assert g.kv_bytes_per_token("flask") == snaps["int8"]["kv_bytes_per_token"]
 
 
 def test_cache_dtype_rejects_unknown_choice():

@@ -67,8 +67,8 @@ def assert_well_formed(t: dict) -> None:
     for e in t["events"]:
         assert e["t"] >= t["t0"] - 1e-9, (t["rid"], e)
     names = [s["name"] for s in t["spans"]]
-    assert "placement" in names
-    p = next(s for s in t["spans"] if s["name"] == "placement")
+    assert "serve.router.place" in names
+    p = next(s for s in t["spans"] if s["name"] == "serve.router.place")
     assert {"f_t", "flask_free", "docker_free", "tier", "reason"} <= set(p["attrs"])
 
 
@@ -140,7 +140,8 @@ def test_deflected_request_trace():
     assert_well_formed(t)
     d = next(e for e in t["events"] if e["name"] == "deflected")
     assert d["attrs"] == {"from_tier": "FLASK", "to_tier": "SERVERLESS"}
-    assert next(s for s in t["spans"] if s["name"] == "placement")["attrs"]["tier"] == "FLASK"
+    assert next(s for s in t["spans"]
+                if s["name"] == "serve.router.place")["attrs"]["tier"] == "FLASK"
     assert t["attrs"]["tier"] == "SERVERLESS" and not t["attrs"]["failed"]
 
 
@@ -264,7 +265,7 @@ def test_engine_loop_trace_chunks_tokens_and_latency_histograms(smoke_cfg):
     tracer.finish(trace)
     [t] = tracer.traces()
     lane = f"engine-sid{seq.sid}"
-    chunks = [s for s in t["spans"] if s["name"] == "prefill_chunk"]
+    chunks = [s for s in t["spans"] if s["name"] == "serve.engine.prefill"]
     assert len(chunks) == 3 and all(s["lane"] == lane for s in chunks)
     assert [c["attrs"]["offset"] for c in chunks] == [0, CHUNK, 2 * CHUNK]
     ev = {e["name"] for e in t["events"]}
@@ -306,7 +307,7 @@ def test_preemption_resume_trace_events(smoke_cfg):
     assert preempted, "tight page pool produced no preemption event"
     t = preempted[0]
     resumes = [s for s in t["spans"]
-               if s["name"] == "prefill" and s["attrs"].get("resume", 0) >= 1]
+               if s["name"] == "serve.engine.prefill" and s["attrs"].get("resume", 0) >= 1]
     assert resumes, "no resume re-prefill span after preemption"
     ev = next(e for e in t["events"] if e["name"] == "preempted")
     assert ev["attrs"]["preemptions"] >= 1 and ev["attrs"]["n_out"] >= 1
@@ -455,7 +456,7 @@ def test_sampler_concurrent_reads_and_flapping_probe():
 def test_chrome_trace_roundtrips_and_names_lanes(tmp_path):
     tracer = Tracer()
     t = tracer.begin(42, model="m")
-    t.add_span("placement", 1.0, 1.1)
+    t.add_span("serve.router.place", 1.0, 1.1)
     t.add_span("execute", 1.2, 2.0, lane="flask", outcome="ok")
     t.add_span("execute", 1.5, 1.9, lane="serverless-hedge", outcome="ok")
     t.event("hedge_fired", t=1.45)
@@ -477,17 +478,6 @@ def test_chrome_trace_roundtrips_and_names_lanes(tmp_path):
     assert len(toks) == 3 and toks[0]["ts"] == pytest.approx(1.3e6)
     # lanes map to distinct tids within the request's process
     assert len({e["tid"] for e in evs if e["ph"] != "M"}) == 4
-
-
-def test_trace_derived_latencies():
-    t = Trace(0, t0=10.0)
-    t.add_tokens("engine-sid0", [10.5, 10.6, 10.8])
-    t.add_tokens("engine-sid1", [10.9, 11.0])
-    assert t.ttft_s() == pytest.approx(0.5)                 # earliest lane
-    assert t.ttft_s("engine-sid1") == pytest.approx(0.9)
-    assert sorted(t.itl_s()) == pytest.approx([0.1, 0.1, 0.2])
-    assert t.lanes() == ["engine-sid0", "engine-sid1"]
-    assert Trace(1).ttft_s() is None and Trace(1).itl_s() == []
 
 
 # ---------------------------------------------------------------------------
