@@ -136,6 +136,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.tracing import span
+from repro.kernels.paged_attention.kernel import live_pages
 from repro.models import get_model
 from repro.serving.paging import (
     NULL_PAGE,
@@ -1311,15 +1312,15 @@ class PagedInferenceEngine(_EngineBase):
 
     def _decode_counts(self, active: List[int]) -> Dict[str, int]:
         """What one batched decode sweeps: every slot's row (``rows``), of
-        which ``live`` decode; the kernel's grid walks the whole row width
-        of each — flat or chained alike — (``pages_walked``, per KV head),
-        while the live rows hold ``pages_live`` pages of the tokens they
-        attend over (cache plus the token being written)."""
-        ps = self.pcfg.page_size
-        live = (self.slot_len[active] + ps) // ps       # ceil((len + 1) / ps)
-        B = self.pcfg.max_slots
-        return {"rows": B, "live": len(active), "pages_walked": B * self._row_width,
-                "pages_live": int(live.sum())}
+        which ``live`` decode; the kernel copies the pages below each row's
+        length — every row's, flat or chained alike — (``pages_walked``, by
+        the kernel's own rule), while the live rows hold ``pages_live``
+        pages of the tokens they attend over (cache plus the token being
+        written)."""
+        ps, width = self.pcfg.page_size, self._row_width
+        walked = live_pages(self.slot_len + 1, ps, width)
+        return {"rows": self.pcfg.max_slots, "live": len(active),
+                "pages_walked": int(walked.sum()), "pages_live": int(walked[active].sum())}
 
     def _prewarm_shape(self, Lp: int, slot: int) -> None:
         """Compile a paged prefill at shape ``Lp`` through an all-null

@@ -97,20 +97,46 @@ def test_paged_prefill_write_quant_kernel_matches_ref(ps, Lp, src_dtype):
             assert d.max() <= 1 and (d > 0).mean() < 1e-3, (name, d.max(), (d > 0).mean())
 
 
-@pytest.mark.parametrize("ps", [4, 8, 16])
-def test_paged_attention_quant_kernel_matches_ref(ps):
+def _walk_table(rng, lens, ps, P):
+    """Distinct pages 1..len(lens)*P in random order, a row's entries past
+    its length on the null page."""
+    pages = rng.permutation(np.arange(1, 1 + len(lens) * P)).reshape(len(lens), P)
+    live = np.arange(P)[None, :] < -(-np.asarray(lens) // ps)[:, None]
+    return np.where(live, pages, NULL_PAGE)
+
+
+# int8 pages of SmolLM-360M's heads (5 x 16 x 64) take 16 a block: a table of
+# 33 pages holds two live blocks and a dead one
+WALK = dict(KV=5, G=3, hd=64, P=33, lens=[1, 16, 256, 257, 528])
+
+
+@pytest.mark.parametrize("ps,walk", [
+    pytest.param(4, False, id="4"),
+    pytest.param(8, False, id="8"),
+    pytest.param(16, False, id="16"),
+    pytest.param(16, True, id="walk-smollm"),
+])
+def test_paged_attention_quant_kernel_matches_ref(ps, walk):
     """Dequant-on-gather inside the decode kernel must match the jnp oracle
-    (gather -> dequantize -> dense attention) on an int8 pool."""
-    from repro.kernels.paged_attention.kernel import paged_attention_grouped
+    (gather -> dequantize -> dense attention) on an int8 pool — over several
+    blocks of pages, rows of 1, ps, ppb * ps, ppb * ps + 1 tokens and a full
+    row side by side, too."""
+    from repro.kernels.paged_attention.kernel import pages_per_block, paged_attention_grouped
     from repro.kernels.paged_attention.ref import paged_attention_ref
 
     rng = np.random.default_rng(11)
-    B, KV, G, hd, NP, n_filled = 3, 2, 2, 16, 12, 9
-    pool_k, pool_v, pool_ks, pool_vs, _, _ = _quant_pools(rng, NP, KV, ps, hd, n_filled)
+    if walk:
+        KV, G, hd, P, lens = WALK["KV"], WALK["G"], WALK["hd"], WALK["P"], WALK["lens"]
+        assert P >= 2 * pages_per_block(ps, KV, hd, 1, P) + 1
+        B, n_filled = len(lens), len(lens) * P
+        tab = _walk_table(rng, lens, ps, P)
+    else:
+        B, KV, G, hd, P, n_filled = 3, 2, 2, 16, 3, 9
+        tab = [[1, 2, 3], [4, 5, NULL_PAGE], [6, 7, 8]]
+        lens = [3 * ps - 1, ps + 2, 2 * ps]
+    pool_k, pool_v, pool_ks, pool_vs, _, _ = _quant_pools(rng, n_filled + 3, KV, ps, hd, n_filled)
     q = jnp.asarray(rng.normal(size=(B, KV, G, hd)), jnp.float32)
-    P = 3
-    tab = jnp.asarray([[1, 2, 3], [4, 5, NULL_PAGE], [6, 7, 8]], jnp.int32)
-    lens = jnp.asarray([3 * ps - 1, ps + 2, 2 * ps], jnp.int32)
+    tab, lens = jnp.asarray(tab, jnp.int32), jnp.asarray(lens, jnp.int32)
     o_kernel = paged_attention_grouped(
         q, pool_k, pool_v, tab, lens, interpret=True, pool_ks=pool_ks, pool_vs=pool_vs
     )
@@ -148,28 +174,47 @@ def test_paged_attention_quant_bounded_error_vs_f32():
     assert err > 0.0, "quantization was a no-op — int8 leg not exercised"
 
 
-@pytest.mark.parametrize("quant", [False, True])
-def test_paged_attention_chained_matches_flat(quant):
+@pytest.mark.parametrize("quant,walk", [
+    pytest.param(False, False, id="False"),
+    pytest.param(True, False, id="True"),
+    pytest.param(False, True, id="walk-f32"),
+    pytest.param(True, True, id="walk-int8"),
+])
+def test_paged_attention_chained_matches_flat(quant, walk):
     """A chained (l1 -> l2 -> page) table must produce EXACTLY the flat
     table's decode output — the indirection is pure addressing, quantized
-    or not."""
-    from repro.kernels.paged_attention.kernel import paged_attention_grouped
-    from repro.kernels.paged_attention.ref import chain_rows
+    or not, over one block of pages or several with dead ones among them."""
+    from repro.kernels.paged_attention.kernel import pages_per_block, paged_attention_grouped
+    from repro.kernels.paged_attention.ref import chain_rows, paged_attention_ref
 
     rng = np.random.default_rng(17)
-    B, KV, G, hd, ps, NP = 2, 2, 2, 16, 8, 12
+    if walk:
+        # SmolLM-360M's heads, three table pages of 16 entries a row: two
+        # live blocks and a dead one, f32 (8 pages a block) or int8 (16)
+        KV, G, hd, ps, tpp, lens = WALK["KV"], WALK["G"], WALK["hd"], 16, 16, WALK["lens"]
+        B, W1 = len(lens), 3
+        P = W1 * tpp
+        assert P >= 2 * pages_per_block(ps, KV, hd, 1 if quant else 4, P) + 1
+        flat = _walk_table(rng, lens, ps, P)
+        l2 = np.concatenate([np.zeros((1, tpp), np.int32), flat.reshape(B * W1, tpp)])
+        l1 = np.where(flat.reshape(B, W1, tpp).any(-1), 1 + np.arange(B * W1).reshape(B, W1), 0)
+        flat, l2, l1 = (jnp.asarray(a, jnp.int32) for a in (flat, l2, l1))
+        NP, n_filled = 1 + B * P, B * P
+    else:
+        B, KV, G, hd, ps, NP, n_filled = 2, 2, 2, 16, 8, 12, 9
+        flat = jnp.asarray([[3, 5, 1, NULL_PAGE], [3, 5, NULL_PAGE, NULL_PAGE]], jnp.int32)
+        l2 = jnp.asarray([[NULL_PAGE, NULL_PAGE], [3, 5], [1, NULL_PAGE], [3, 5]], jnp.int32)
+        l1 = jnp.asarray([[1, 2], [3, 0]], jnp.int32)
+        lens = [2 * ps + 3, ps + 2]
     if quant:
-        pool_k, pool_v, pool_ks, pool_vs, _, _ = _quant_pools(rng, NP, KV, ps, hd, 9)
+        pool_k, pool_v, pool_ks, pool_vs, _, _ = _quant_pools(rng, NP, KV, ps, hd, n_filled)
     else:
         pool_k = jnp.asarray(rng.normal(size=(NP, KV, ps, hd)), jnp.float32)
         pool_v = jnp.asarray(rng.normal(size=(NP, KV, ps, hd)), jnp.float32)
         pool_ks = pool_vs = None
-    flat = jnp.asarray([[3, 5, 1, NULL_PAGE], [3, 5, NULL_PAGE, NULL_PAGE]], jnp.int32)
-    l2 = jnp.asarray([[NULL_PAGE, NULL_PAGE], [3, 5], [1, NULL_PAGE], [3, 5]], jnp.int32)
-    l1 = jnp.asarray([[1, 2], [3, 0]], jnp.int32)
     assert jnp.array_equal(chain_rows(l1, l2), flat)
     q = jnp.asarray(rng.normal(size=(B, KV, G, hd)), jnp.float32)
-    lens = jnp.asarray([2 * ps + 3, ps + 2], jnp.int32)
+    lens = jnp.asarray(lens, jnp.int32)
     o_flat = paged_attention_grouped(
         q, pool_k, pool_v, flat, lens, interpret=True, pool_ks=pool_ks, pool_vs=pool_vs
     )
@@ -178,6 +223,9 @@ def test_paged_attention_chained_matches_flat(quant):
         pool_ks=pool_ks, pool_vs=pool_vs, l2_tab=l2,
     )
     assert jnp.array_equal(o_flat, o_chain)
+    o_ref = paged_attention_ref(
+        q, pool_k, pool_v, l1, lens, pool_ks=pool_ks, pool_vs=pool_vs, l2_tab=l2)
+    assert float(jnp.max(jnp.abs(o_chain - o_ref))) < 2e-5
 
 
 # ---------------------------------------------------------------------------

@@ -298,13 +298,24 @@ def test_prefix_cache_allocator_invariants_under_random_interleavings(ops):
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("lens", [[1, 5, 17, 32], [8, 8, 8, 8], [31, 2, 16, 1]])
-def test_paged_attention_kernel_matches_ref(lens):
-    from repro.kernels.paged_attention.kernel import paged_attention_grouped
+@pytest.mark.parametrize("lens,KV,G,hd,ps,P", [
+    pytest.param([1, 5, 17, 32], 2, 3, 16, 8, 4, id="lens0"),
+    pytest.param([8, 8, 8, 8], 2, 3, 16, 8, 4, id="lens1"),
+    pytest.param([31, 2, 16, 1], 2, 3, 16, 8, 4, id="lens2"),
+    # several blocks of ``pages_per_block`` pages a row (8 here) and a dead
+    # block in every batch: rows of 1, ps, ppb * ps, ppb * ps + 1 tokens and
+    # a full row side by side, at SmolLM-360M's and Granite-8B's head shapes
+    pytest.param([1, 16, 128, 129, 272], 5, 3, 64, 16, 17, id="walk-smollm"),
+    pytest.param([272, 1, 129, 2, 16, 128], 8, 4, 128, 16, 17, id="walk-granite"),
+])
+def test_paged_attention_kernel_matches_ref(lens, KV, G, hd, ps, P):
+    from repro.kernels.paged_attention.kernel import pages_per_block, paged_attention_grouped
     from repro.kernels.paged_attention.ref import paged_attention_ref
 
     rng = np.random.default_rng(0)
-    B, KV, G, hd, ps, P, NP = 4, 2, 3, 16, 8, 4, 20
+    B, NP = len(lens), 1 + len(lens) * P
+    ppb = pages_per_block(ps, KV, hd, 4, P)
+    assert P == ppb or P >= 2 * ppb + 1       # one block, or a dead one besides
     q = jnp.asarray(rng.normal(size=(B, KV, G, hd)), jnp.float32)
     pk = jnp.asarray(rng.normal(size=(NP, KV, ps, hd)), jnp.float32)
     pv = jnp.asarray(rng.normal(size=(NP, KV, ps, hd)), jnp.float32)

@@ -87,8 +87,9 @@ def test_engine_step_span_tree_and_decode_counts(cfg, tmp_path, chained, walked)
     """Each ``step()`` is one ``serve.engine.step`` holding its phases; a
     step that decodes holds a ``serve.engine.decode`` whose counts are the
     engine's slot state at the dispatch, then a ``device_wait``. The kernel
-    walks each row's whole width: the flat table's 4 pages, or one
-    16-entry page of a chained table."""
+    copies the pages below each row's length, every row's, at most the row
+    width: the flat table's 4 pages, or one 16-entry page of a chained
+    table."""
     eng = _engine(cfg, chained=chained)
     eng.name = "tier-a"
     for i, n in enumerate((5, 20, 40)):
@@ -100,7 +101,7 @@ def test_engine_step_span_tree_and_decode_counts(cfg, tmp_path, chained, walked)
     def recording(*args):
         live = [int(eng.slot_len[i]) for i, s in enumerate(eng.slot_seq)
                 if s is not None and not eng._chunking[i]]
-        seen.append(live)
+        seen.append((live, [int(n) for n in eng.slot_len]))
         return decode(*args)
 
     eng._decode = recording
@@ -119,9 +120,11 @@ def test_engine_step_span_tree_and_decode_counts(cfg, tmp_path, chained, walked)
         if "serve.engine.decode" in names:
             i = names.index("serve.engine.decode")
             assert names[i + 1] == "serve.engine.device_wait"
-    for d, live in zip(decodes, seen):
+    for d, (live, every) in zip(decodes, seen):
         c = d["stats"]
-        assert c["rows"] == 3 and c["pages_walked"] == 3 * walked
+        assert c["rows"] == 3
+        assert c["pages_walked"] == sum(min(-(-(n + 1) // PS), walked) for n in every)
+        assert c["pages_walked"] < 3 * walked
         assert c["live"] == len(live) and c["live"] <= c["rows"]
         assert c["pages_live"] == sum(-(-(n + 1) // PS) for n in live)
         assert c["pages_live"] <= c["pages_walked"]
@@ -130,6 +133,28 @@ def test_engine_step_span_tree_and_decode_counts(cfg, tmp_path, chained, walked)
     assert all(c["stats"]["padded"] >= c["stats"]["tokens"] for c in chunks)
     # the final chunk reads its token: a device wait inside the prefill span
     assert any(_children(spans, c) for c in chunks)
+
+
+@pytest.mark.parametrize("chained", [False, True])
+def test_decode_counts_pages_walked_is_the_kernels_walk(cfg, chained):
+    """``pages_walked`` counts what the decode kernel copies: the pages
+    below each row's length (cache plus the token being written), by the
+    kernel's own ``live_pages``, every row's. On rows of different lengths
+    that stays below rows x row width, which the kernel no longer walks."""
+    from repro.kernels.paged_attention.kernel import live_pages
+
+    eng = _engine(cfg, slots=4, new=20, chained=chained)
+    for i, n in enumerate((3, 17, 40)):
+        eng.submit(_prompt(cfg, n, i))
+    for _ in range(6):
+        eng.step()
+    active = [i for i, s in enumerate(eng.slot_seq) if s is not None and not eng._chunking[i]]
+    assert len(active) == 3 and len(set(eng.slot_len[active] // PS)) == 3
+    c = eng._decode_counts(active)
+    width = eng._row_width
+    assert c["pages_walked"] == int(live_pages(eng.slot_len + 1, PS, width).sum())
+    assert c["pages_live"] == sum(-(-(int(n) + 1) // PS) for n in eng.slot_len[active])
+    assert c["pages_live"] < c["pages_walked"] < c["rows"] * width
 
 
 def test_admit_stamp_is_set_once_and_kept_across_preemption(cfg):

@@ -1,6 +1,7 @@
 """Compile the serving path's Pallas kernels for a TPU v5e chip that is
 described, not attached, at SmolLM-360M widths (15 query heads over 5 KV
-heads, head_dim 64, page size 16, 1024-token rows, bf16 and int8 pools).
+heads, head_dim 64, page size 16, 1024-token rows, bf16 and int8 pools),
+and the paged decode at Granite-8B's too (32 over 8, head_dim 128).
 
 Interpret-mode tests cannot see what Mosaic refuses — unaligned blocks,
 too much VMEM — so each case lowers the kernel with ``interpret=False``,
@@ -67,11 +68,20 @@ def _s(shape, dtype):
     return jax.ShapeDtypeStruct(shape, dtype)
 
 
-@pytest.mark.parametrize("pool", ["bf16", "int8", "chained"])
-def test_paged_decode_compiles(pool, one_chip):
+# Granite-8B's attention (32 query heads over 8 KV heads of 128), the batch
+# tier's decode of the granite-8b-18l cell
+GRANITE = dict(KV=8, G=4, HD=128)
+
+
+@pytest.mark.parametrize("pool,widths", [
+    pytest.param(pool, widths, id=pool if widths is None else f"granite-{pool}")
+    for widths in (None, GRANITE) for pool in ("bf16", "int8", "chained")
+])
+def test_paged_decode_compiles(pool, widths, one_chip):
+    kv, g, hd = (KV, G, HD) if widths is None else (widths["KV"], widths["G"], widths["HD"])
     dtype = jnp.int8 if pool == "int8" else jnp.bfloat16
-    q = _s((B, KV, G, HD), jnp.bfloat16)
-    pk = _s((NUM_PAGES, KV, PS, HD), dtype)
+    q = _s((B, kv, g, hd), jnp.bfloat16)
+    pk = _s((NUM_PAGES, kv, PS, hd), dtype)
     lens = _s((B,), jnp.int32)
     if pool == "chained":
         tpp = PS                                   # table pages of 16 entries
@@ -81,7 +91,7 @@ def test_paged_decode_compiles(pool, one_chip):
             q, k, v, t, n, interpret=False, l2_tab=l2)
         args = (q, pk, pk, l1, lens, l2)
     elif pool == "int8":
-        sc = _s((NUM_PAGES, KV, PS, 1), jnp.bfloat16)
+        sc = _s((NUM_PAGES, kv, PS, 1), jnp.bfloat16)
         fn = lambda q, k, v, t, n, ks, vs: paged_attention_grouped(
             q, k, v, t, n, interpret=False, pool_ks=ks, pool_vs=vs)
         args = (q, pk, pk, _s((B, P), jnp.int32), lens, sc, sc)
